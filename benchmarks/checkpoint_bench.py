@@ -6,8 +6,6 @@ and prints the analytic cold-start cost model at headline scale so the
 Scenario XI simulation numbers have a closed-form anchor next to them.
 
 Rows follow the repo convention: {name, us_per_call, derived, metrics}.
-Requires jax (the store serialises pytrees); swarm_bench carries the
-no-jax rows.
 """
 from __future__ import annotations
 
@@ -129,4 +127,6 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bench()

@@ -10,6 +10,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
 
     # ---- paper tables I-IV (the reproduction) -------------------------- #

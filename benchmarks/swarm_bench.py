@@ -484,6 +484,8 @@ def main(argv=None) -> None:
                          "(e.g. 50,2048 or the CI smoke 8,256); with "
                          "--json, rows are merged into the file by name")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.scenario_x:
         n = int(args.scenario_x)
         rows = bench_scenario_x(

@@ -46,20 +46,26 @@ def _image_files(d: str) -> List[str]:
     return ["manifest.json"] + pieces
 
 
-def pack_step_image(d: str) -> bytes:
+def pack_step_image(d: str) -> bytearray:
     """Pack a committed step directory into the canonical image bytes the
     swarm manifest hashes: magic, a json file table, then the files'
-    bytes concatenated in table order."""
+    bytes concatenated in table order.  The files are read straight into
+    one preallocated buffer, so packing holds one copy of the step."""
     files = _image_files(d)
-    blobs = []
-    table = []
-    for fn in files:
+    sizes = [os.path.getsize(os.path.join(d, fn)) for fn in files]
+    table = [{"name": fn, "size": n} for fn, n in zip(files, sizes)]
+    header = IMAGE_MAGIC + json.dumps({"files": table},
+                                      sort_keys=True).encode() + b"\n"
+    image = bytearray(len(header) + sum(sizes))
+    image[:len(header)] = header
+    view = memoryview(image)
+    ofs = len(header)
+    for fn, n in zip(files, sizes):
         with open(os.path.join(d, fn), "rb") as f:
-            b = f.read()
-        table.append({"name": fn, "size": len(b)})
-        blobs.append(b)
-    header = json.dumps({"files": table}, sort_keys=True).encode() + b"\n"
-    return IMAGE_MAGIC + header + b"".join(blobs)
+            if f.readinto(view[ofs:ofs + n]) != n:
+                raise IOError(f"{fn} changed size while being packed")
+        ofs += n
+    return image
 
 
 def unpack_step_image(image, dest_dir: str) -> List[str]:
@@ -183,7 +189,7 @@ class CheckpointStore:
         return f"ckpt-{os.path.basename(os.path.normpath(self.root))}" \
                f"-step{step:08d}"
 
-    def pack_image(self, step: Optional[int] = None) -> bytes:
+    def pack_image(self, step: Optional[int] = None) -> bytearray:
         """The committed step's canonical swarm image bytes."""
         step = step if step is not None else self.latest_step()
         assert step is not None, "no committed checkpoint found"

@@ -20,32 +20,34 @@ Three interchangeable backends hide behind the same API, mirroring the
 repo's kernel discipline (`repro.kernels.ssd.ops`: reference impl +
 differential tests + selectable fast path):
 
-  * ``numpy``  — always available, the default on CPU-only images;
-  * ``jax``    — jitted `jnp` version of the same math;
-  * ``pallas`` — the rarest-first scoring inner loop as a Pallas kernel
-    (interpret mode on CPU, compiled on TPU), argsort staying in XLA.
+  * ``numpy``  — the host reference and the default;
+  * ``jax``    — jitted `jnp` version of the same math, on JAX's default
+    device;
+  * ``pallas`` — `rarest_keys` and `island_has` as Pallas kernels
+    (interpreted on CPU, Mosaic-compiled on TPU); the other kernels share
+    the jax path.
 
 `set_backend` / the ``REPRO_SWARM_BACKEND`` env var select globally;
-every function also takes an explicit ``backend=``.  Unknown or
-unavailable backends fall back to numpy, so CPU-only CI never needs jax.
-Differential tests (tests/test_swarm_batch.py) assert all backends
-reproduce the scalar decisions bit-for-bit.
+every function also takes an explicit ``backend=``.  An unknown backend
+raises: a run never silently degrades to numpy.  The jax/pallas wrappers
+pad every varying dimension to a power-of-two bucket (pad rows and
+candidates are inert), so a run compiles each kernel once per bucket, not
+once per tick.  Differential tests (tests/test_swarm_batch.py) assert all
+backends reproduce the scalar decisions bit-for-bit.
 """
 from __future__ import annotations
 
+import collections
 import os
+from functools import partial
 from typing import List, Optional, Sequence
 
+import jax
+import jax.experimental.pallas as pl
+import jax.numpy as jnp
 import numpy as np
 
-try:  # CPU-only protocol CI installs no jax; everything degrades to numpy
-    import jax
-    import jax.numpy as jnp
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised on the no-jax CI image
-    jax = None
-    jnp = None
-    _HAVE_JAX = False
+from repro.kernels import pallas_on_platform
 
 # sentinel key for pieces a row must not request (held, pending, invalid):
 # larger than any real composite key so they argsort to the back
@@ -58,21 +60,53 @@ KEY_INF32 = np.int32(2 ** 30)
 
 _backend = os.environ.get("REPRO_SWARM_BACKEND", "numpy")
 
+# (kernel, platform) -> calls whose output came back from that platform:
+# the proof that a jax/pallas run put its kernels on the device it claims
+DEVICE_CALLS: collections.Counter = collections.Counter()
+
 
 def available_backends() -> List[str]:
-    return ["numpy"] + (["jax", "pallas"] if _HAVE_JAX else [])
+    return ["numpy", "jax", "pallas"]
+
+
+def _check_backend(name: str) -> str:
+    if name not in available_backends():
+        raise ValueError(f"unknown or unavailable swarm kernel backend "
+                         f"{name!r}; choose one of {available_backends()}")
+    return name
 
 
 def set_backend(name: str) -> str:
-    """Select the default backend; unavailable ones fall back to numpy."""
+    """Select the default backend; raises on an unknown one."""
     global _backend
-    _backend = name if name in available_backends() else "numpy"
+    _backend = _check_backend(name)
     return _backend
 
 
 def get_backend(backend: Optional[str] = None) -> str:
-    b = backend if backend is not None else _backend
-    return b if b in available_backends() else "numpy"
+    return _check_backend(backend if backend is not None else _backend)
+
+
+def _bucket(n: int) -> int:
+    """Padded extent of a varying kernel dimension: the next power of
+    two, at least 8 (one sublane tile)."""
+    return max(8, 1 << max(int(n) - 1, 0).bit_length())
+
+
+def _pad(a: np.ndarray, shape, fill) -> np.ndarray:
+    """`a` in the top-left corner of a `shape` array filled with `fill`."""
+    a = np.asarray(a)
+    if a.shape == tuple(shape):
+        return a
+    out = np.full(shape, fill, dtype=a.dtype)
+    out[tuple(slice(0, d) for d in a.shape)] = a
+    return out
+
+
+def _fetch(name: str, out: jax.Array) -> np.ndarray:
+    """Device result -> host numpy, counted under the device's platform."""
+    DEVICE_CALLS[name, next(iter(out.devices())).platform] += 1
+    return np.asarray(out)
 
 
 # ====================== rarest-first scoring ============================ #
@@ -92,54 +126,48 @@ def rarest_keys_np(counts: np.ndarray, offsets: np.ndarray,
     return (counts.astype(np.int64)[None, :] * n + rot) * n + p[None, :]
 
 
-if _HAVE_JAX:
-    from functools import partial
+# rows per Pallas grid step (a multiple of the 8-row sublane tile)
+_ROW_BLOCK = 256
 
-    @partial(jax.jit, static_argnames=("n_pieces", "impl", "interpret"))
-    def _rarest_keys_jax(counts, offsets, n_pieces: int,
-                         impl: str = "jnp", interpret: bool = True):
-        # int32 throughout (jax runs without x64 here): the composite key
-        # needs counts * n^2 < 2^31, which holds for every simulated
-        # swarm (counts <= N; see _rarest_keys_pallas)
-        if impl == "pallas":
-            return _rarest_keys_pallas(counts, offsets, n_pieces,
-                                       interpret=interpret)
-        n = max(int(n_pieces), 1)
-        p = jnp.arange(n, dtype=jnp.int32)
-        rot = (p[None, :] + offsets.astype(jnp.int32)[:, None]) % n
-        return (counts.astype(jnp.int32)[None, :] * n + rot) * n + p[None, :]
 
-    def _rarest_keys_pallas(counts, offsets, n_pieces: int,
-                            interpret: bool = True):
-        """Pallas scoring kernel: the fused multiply-add + rotated-modulo
-        inner loop of the rarest-first key computation, one grid row per
-        node block.  int32 on-chip (TPU-native); the (counts * n * n)
-        product must stay below 2^31, which holds for every simulated
-        swarm (counts <= N, N * P^2 < 2^31 up to N=2000, P=1024)."""
-        import jax.experimental.pallas as pl
+def _rarest_keys_kernel(counts_ref, off_ref, out_ref, *, n: int):
+    """One (rows, n) block of composite keys.  ``off`` arrives reduced
+    mod n, so the rotation is one conditional subtract (no vector rem)."""
+    c = counts_ref[...]                                  # (1, n)
+    off = off_ref[...]                                   # (rows, 1)
+    p = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    rot = p + off
+    rot = jnp.where(rot >= n, rot - n, rot)
+    out_ref[...] = (c * n + rot) * n + p
 
-        n = max(int(n_pieces), 1)
-        rows = offsets.shape[0]
 
-        def kernel(counts_ref, off_ref, out_ref):
-            c = counts_ref[...].astype(jnp.int32)            # (1, n)
-            off = off_ref[...].astype(jnp.int32)             # (1, 1)
-            p = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-            rot = jax.lax.rem(p + off, jnp.int32(n))
-            out_ref[...] = (c * n + rot) * n + p
+def _rarest_keys_pallas(counts, offsets, n: int, interpret: bool):
+    rows = offsets.shape[0]
+    blk = min(rows, _ROW_BLOCK)
+    return pl.pallas_call(
+        partial(_rarest_keys_kernel, n=n),
+        grid=(rows // blk,),
+        in_specs=[pl.BlockSpec((1, n), lambda i: (0, 0)),
+                  pl.BlockSpec((blk, 1), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((blk, n), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.int32),
+        interpret=interpret,
+    )(counts, offsets)
 
-        return pl.pallas_call(
-            kernel,
-            grid=(rows,),
-            in_specs=[
-                pl.BlockSpec((1, n), lambda i: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows, n), jnp.int32),
-            interpret=interpret,
-        )(counts.astype(jnp.int32)[None, :],
-          offsets.astype(jnp.int32)[:, None])
+
+@partial(jax.jit, static_argnames=("n_pieces", "impl"))
+def _rarest_keys_jax(counts, offsets, n_pieces: int, impl: str = "jnp"):
+    # int32 throughout (jax runs without x64): the composite key needs
+    # counts * n^2 < 2^31, which holds for every simulated swarm
+    # (counts <= N, N * P^2 < 2^31 up to N=2000, P=1024)
+    n = max(int(n_pieces), 1)
+    c = counts.astype(jnp.int32)[None, :]
+    off = offsets.astype(jnp.int32)[:, None]          # already mod n
+    if impl == "pallas":
+        return pallas_on_platform(_rarest_keys_pallas, c, off, n=n)
+    p = jnp.arange(n, dtype=jnp.int32)[None, :]
+    rot = (p + off) % n
+    return (c * n + rot) * n + p
 
 
 def rarest_keys(counts: np.ndarray, offsets: np.ndarray, n_pieces: int,
@@ -156,11 +184,14 @@ def rarest_keys(counts: np.ndarray, offsets: np.ndarray, n_pieces: int,
     b = get_backend(backend)
     if b == "numpy":
         return rarest_keys_np(counts, offsets, n_pieces)
-    impl = "pallas" if b == "pallas" else "jnp"
-    out = _rarest_keys_jax(jnp.asarray(np.asarray(counts)),
-                           jnp.asarray(np.asarray(offsets)),
-                           int(n_pieces), impl=impl)
-    return np.asarray(out, dtype=np.int64)
+    offsets = np.asarray(offsets, dtype=np.int64)
+    rows = offsets.shape[0]
+    # reduce on the host: int64 rotations would wrap in int32 on device
+    off = _pad(offsets % max(int(n_pieces), 1), (_bucket(rows),), 0)
+    out = _rarest_keys_jax(np.asarray(counts, dtype=np.int32),
+                           off.astype(np.int32), int(n_pieces),
+                           impl="pallas" if b == "pallas" else "jnp")
+    return _fetch("rarest_keys", out)[:rows].astype(np.int64)
 
 
 def rarest_orders(missing: np.ndarray, counts: np.ndarray,
@@ -213,37 +244,48 @@ def island_has_np(have: np.ndarray, member: np.ndarray) -> np.ndarray:
     return (m @ h) > 0
 
 
-if _HAVE_JAX:
-    @jax.jit
-    def _island_has_jax(have, member):
-        return (member.astype(jnp.int32) @ have.astype(jnp.int32)) > 0
+# have-matrix rows per Pallas grid step of the island reduction
+_ISLAND_BLOCK = 512
 
-    def _island_has_pallas(have, member, interpret: bool = True):
-        """Pallas island-availability kernel: one grid step per island,
-        reducing that island's member rows over the have-matrix as a
-        (1, N) x (N, P) dot — the MXU-native shape of the reduction."""
-        import jax.experimental.pallas as pl
 
-        k, n = member.shape
-        p = have.shape[1]
+def _island_has_kernel(member_ref, have_ref, out_ref):
+    """Accumulate one (K, block) x (block, P) slice of the membership x
+    have product; the output block stays resident across the N axis."""
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-        def kernel(member_ref, have_ref, out_ref):
-            m = member_ref[...].astype(jnp.float32)          # (1, n)
-            h = have_ref[...].astype(jnp.float32)            # (n, p)
-            out_ref[...] = jnp.dot(
-                m, h, preferred_element_type=jnp.float32) > 0
+    out_ref[...] += jnp.dot(member_ref[...], have_ref[...],
+                            preferred_element_type=jnp.float32
+                            ).astype(jnp.int32)
 
-        return pl.pallas_call(
-            kernel,
-            grid=(k,),
-            in_specs=[
-                pl.BlockSpec((1, n), lambda i: (i, 0)),
-                pl.BlockSpec((n, p), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, p), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((k, p), jnp.bool_),
-            interpret=interpret,
-        )(member.astype(jnp.int32), have.astype(jnp.int32))
+
+def _island_has_pallas(have, member, interpret: bool):
+    """Island availability counts as an MXU reduction over bounded
+    (block, P) have-tiles (0/1 operands: the f32 sums are exact)."""
+    k, n = member.shape
+    p = have.shape[1]
+    blk = min(n, _ISLAND_BLOCK)
+    return pl.pallas_call(
+        _island_has_kernel,
+        grid=(n // blk,),
+        in_specs=[pl.BlockSpec((k, blk), lambda i: (0, i)),
+                  pl.BlockSpec((blk, p), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((k, p), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, p), jnp.int32),
+        interpret=interpret,
+    )(member, have)
+
+
+@partial(jax.jit, static_argnames=("impl",))
+def _island_has_jax(have, member, impl: str = "jnp"):
+    if impl == "pallas":
+        cnt = pallas_on_platform(_island_has_pallas,
+                                 have.astype(jnp.float32),
+                                 member.astype(jnp.float32))
+    else:
+        cnt = member.astype(jnp.int32) @ have.astype(jnp.int32)
+    return cnt > 0
 
 
 def island_has(have: np.ndarray, member: np.ndarray,
@@ -252,13 +294,16 @@ def island_has(have: np.ndarray, member: np.ndarray,
     b = get_backend(backend)
     if b == "numpy":
         return island_has_np(have, member)
-    hj = jnp.asarray(np.asarray(have, dtype=np.int32))
-    mj = jnp.asarray(np.asarray(member, dtype=np.int32))
-    if b == "pallas":
-        out = _island_has_pallas(hj, mj)
-    else:
-        out = _island_has_jax(hj, mj)
-    return np.asarray(out, dtype=bool)
+    k, n = np.shape(member)
+    nb = _bucket(n)
+    # island count padded to a sublane tile, node count to a bucket that
+    # the Pallas have-tile divides; pad rows/islands are all-False
+    kb = -(-max(k, 1) // 8) * 8
+    hv = _pad(np.asarray(have, dtype=bool), (nb, np.shape(have)[1]), False)
+    mb = _pad(np.asarray(member, dtype=bool), (kb, nb), False)
+    out = _island_has_jax(hv, mb,
+                          impl="pallas" if b == "pallas" else "jnp")
+    return _fetch("island_has", out)[:k].astype(bool)
 
 
 def min_island_cost(avail: np.ndarray, cost: np.ndarray) -> np.ndarray:
@@ -350,22 +395,21 @@ def choke_order_np(recv: np.ndarray, sent: np.ndarray, cand: np.ndarray,
     return order.astype(np.int32)
 
 
-if _HAVE_JAX:
-    @jax.jit
-    def _choke_order_jax(recv, sent, cand, ranks):
-        r1 = jnp.where(cand, recv, -1.0)
-        r2 = jnp.where(cand, sent, -1.0)
-        # int32 keys (jax runs without x64): callers packing cost above
-        # the name rank must keep cost * shift + rank < 2^31
-        rk = ranks if ranks.ndim == 2 else ranks[None, :]
-        maxr = jnp.max(rk) + 1 if rk.size else 1
-        nm = jnp.where(cand, rk, maxr).astype(jnp.int32)
-        order = jnp.argsort(nm, axis=1, stable=True)
-        for key in (-r2, -r1):
-            k = jnp.take_along_axis(key, order, axis=1)
-            order = jnp.take_along_axis(
-                order, jnp.argsort(k, axis=1, stable=True), axis=1)
-        return order.astype(jnp.int32)
+@jax.jit
+def _choke_order_jax(recv, sent, cand, ranks):
+    r1 = jnp.where(cand, recv, -1.0)
+    r2 = jnp.where(cand, sent, -1.0)
+    # int32 keys (jax runs without x64): callers packing cost above
+    # the name rank must keep cost * shift + rank < 2^31
+    rk = ranks if ranks.ndim == 2 else ranks[None, :]
+    maxr = jnp.max(rk) + 1 if rk.size else 1
+    nm = jnp.where(cand, rk, maxr).astype(jnp.int32)
+    order = jnp.argsort(nm, axis=1, stable=True)
+    for key in (-r2, -r1):
+        k = jnp.take_along_axis(key, order, axis=1)
+        order = jnp.take_along_axis(
+            order, jnp.argsort(k, axis=1, stable=True), axis=1)
+    return order.astype(jnp.int32)
 
 
 def choke_order(recv: np.ndarray, sent: np.ndarray, cand: np.ndarray,
@@ -374,13 +418,18 @@ def choke_order(recv: np.ndarray, sent: np.ndarray, cand: np.ndarray,
     b = get_backend(backend)
     if b == "numpy":
         return choke_order_np(recv, sent, cand, ranks)
-    # the pallas backend shares the jax ranking path: the scoring kernel
-    # only covers the rarest-first inner loop, where it wins
-    out = _choke_order_jax(jnp.asarray(np.asarray(recv, dtype=np.float32)),
-                           jnp.asarray(np.asarray(sent, dtype=np.float32)),
-                           jnp.asarray(np.asarray(cand, dtype=bool)),
-                           jnp.asarray(np.asarray(ranks, dtype=np.int32)))
-    return np.asarray(out, dtype=np.int32)
+    # the pallas backend shares the jax ranking path: sorts stay in XLA.
+    # Pad holders and columns are non-candidates: they sort behind every
+    # real column, so the leading (H, C) block is the unpadded ranking.
+    h, c = np.shape(cand)
+    shape = (_bucket(h), _bucket(c))
+    rk = np.asarray(ranks, dtype=np.int32)
+    rk = _pad(rk, shape if rk.ndim == 2 else shape[1:], 0)
+    out = _choke_order_jax(
+        _pad(np.asarray(recv, dtype=np.float32), shape, 0.0),
+        _pad(np.asarray(sent, dtype=np.float32), shape, 0.0),
+        _pad(np.asarray(cand, dtype=bool), shape, False), rk)
+    return _fetch("choke_order", out)[:h, :c]
 
 
 # ==================== fused request matching ============================ #
@@ -451,105 +500,37 @@ def match_requests_np(orders: np.ndarray, n_walk: np.ndarray,
     return picks
 
 
-if _HAVE_JAX:
-    @jax.jit
-    def _match_requests_jax(orders, n_walk, budgets, cand, cand_ok,
-                            cand_key, have, full):
-        R, P = orders.shape
-        safe = jnp.where(cand >= 0, cand, 0)
-        hv = have[safe] | full[safe][:, :, None]             # (R, C, P)
-        inf = jnp.int32(KEY_INF32)
-        key0 = jnp.where(cand_ok, cand_key.astype(jnp.int32), inf)
-        ridx = jnp.arange(R)
+@jax.jit
+def _match_requests_jax(orders, n_walk, budgets, cand, cand_ok,
+                        cand_key, have, full):
+    R, P = orders.shape
+    safe = jnp.where(cand >= 0, cand, 0)
+    hv = have[safe] | full[safe][:, :, None]             # (R, C, P)
+    inf = jnp.int32(KEY_INF32)
+    key0 = jnp.where(cand_ok, cand_key.astype(jnp.int32), inf)
+    ridx = jnp.arange(R)
 
-        def body(k, carry):
-            picks, taken, budget = carry
-            act = (budget > 0) & (k < n_walk) & ~jnp.all(taken, axis=1)
-            p = orders[:, k]
-            col = jnp.take_along_axis(
-                hv, p[:, None, None], axis=2)[:, :, 0]       # (R, C)
-            okk = ~taken & col & act[:, None]
-            sel = okk.any(axis=1)
-            c = jnp.argmin(jnp.where(okk, key0, inf), axis=1)
-            val = jnp.take_along_axis(cand, c[:, None], axis=1)[:, 0]
-            picks = picks.at[:, k].set(
-                jnp.where(sel, val, picks[:, k]))
-            taken = taken.at[ridx, c].set(taken[ridx, c] | sel)
-            budget = budget - sel.astype(budget.dtype)
-            return picks, taken, budget
+    def body(k, carry):
+        picks, taken, budget = carry
+        act = (budget > 0) & (k < n_walk) & ~jnp.all(taken, axis=1)
+        p = orders[:, k]
+        col = jnp.take_along_axis(
+            hv, p[:, None, None], axis=2)[:, :, 0]       # (R, C)
+        okk = ~taken & col & act[:, None]
+        sel = okk.any(axis=1)
+        c = jnp.argmin(jnp.where(okk, key0, inf), axis=1)
+        val = jnp.take_along_axis(cand, c[:, None], axis=1)[:, 0]
+        picks = picks.at[:, k].set(
+            jnp.where(sel, val, picks[:, k]))
+        taken = taken.at[ridx, c].set(taken[ridx, c] | sel)
+        budget = budget - sel.astype(budget.dtype)
+        return picks, taken, budget
 
-        picks0 = jnp.full((R, P), -1, dtype=jnp.int32)
-        picks, _, _ = jax.lax.fori_loop(
-            0, P, body,
-            (picks0, ~cand_ok, budgets.astype(jnp.int32)))
-        return picks
-
-    def _match_requests_pallas(orders, n_walk, budgets, cand, cand_ok,
-                               cand_key, have, full,
-                               interpret: bool = True):
-        """Pallas request-matching kernel: one grid program per row walks
-        that row's piece order with the (candidate-availability, key,
-        busy-mask) state resident in the program — the per-row greedy
-        inner loop the numpy/jax paths vectorize across rows."""
-        import jax.experimental.pallas as pl
-
-        R, P = orders.shape
-        C = cand.shape[1]
-        safe = jnp.where(cand >= 0, cand, 0)
-        hv = (have[safe] | full[safe][:, :, None]).astype(jnp.int32)
-        inf = int(KEY_INF32)  # plain int: pallas kernels can't capture arrays
-
-        def kernel(ord_ref, walk_ref, bud_ref, cand_ref, ok_ref,
-                   key_ref, hv_ref, out_ref):
-            order = ord_ref[...]                             # (1, P)
-            okrow = ok_ref[...][0] != 0                      # (C,)
-            keyrow = jnp.where(okrow, key_ref[...][0], inf)  # (C,)
-            hvrow = hv_ref[...][0]                           # (C, P)
-            candrow = cand_ref[...][0]                       # (C,)
-            walk = walk_ref[...][0, 0]
-
-            def body(k, carry):
-                out, taken, bud = carry
-                act = (bud > 0) & (k < walk) & jnp.any(~taken)
-                p = order[0, k]
-                col = jax.lax.dynamic_index_in_dim(
-                    hvrow, p, axis=1, keepdims=False)        # (C,)
-                okk = ~taken & (col != 0) & act
-                sel = jnp.any(okk)
-                c = jnp.argmin(jnp.where(okk, keyrow, inf))
-                out = out.at[0, k].set(
-                    jnp.where(sel, candrow[c], out[0, k]))
-                taken = taken.at[c].set(taken[c] | sel)
-                bud = bud - sel.astype(bud.dtype)
-                return out, taken, bud
-
-            init = (jnp.full((1, P), -1, dtype=jnp.int32),
-                    ~okrow, bud_ref[...][0, 0])
-            out, _, _ = jax.lax.fori_loop(0, P, body, init)
-            out_ref[...] = out
-
-        return pl.pallas_call(
-            kernel,
-            grid=(R,),
-            in_specs=[
-                pl.BlockSpec((1, P), lambda i: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i: (i, 0)),
-                pl.BlockSpec((1, C), lambda i: (i, 0)),
-                pl.BlockSpec((1, C), lambda i: (i, 0)),
-                pl.BlockSpec((1, C), lambda i: (i, 0)),
-                pl.BlockSpec((1, C, P), lambda i: (i, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, P), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((R, P), jnp.int32),
-            interpret=interpret,
-        )(orders.astype(jnp.int32),
-          n_walk.astype(jnp.int32)[:, None],
-          budgets.astype(jnp.int32)[:, None],
-          cand.astype(jnp.int32),
-          cand_ok.astype(jnp.int32),
-          cand_key.astype(jnp.int32),
-          hv)
+    picks0 = jnp.full((R, P), -1, dtype=jnp.int32)
+    picks, _, _ = jax.lax.fori_loop(
+        0, P, body,
+        (picks0, ~cand_ok, budgets.astype(jnp.int32)))
+    return picks
 
 
 def match_requests(orders: np.ndarray, n_walk: np.ndarray,
@@ -562,19 +543,24 @@ def match_requests(orders: np.ndarray, n_walk: np.ndarray,
             or cand.shape[1] == 0:
         return match_requests_np(orders, n_walk, budgets, cand,
                                  cand_ok, cand_key, have, full)
-    oj = jnp.asarray(np.asarray(orders, dtype=np.int32))
-    wj = jnp.asarray(np.asarray(n_walk, dtype=np.int32))
-    bj = jnp.asarray(np.asarray(budgets, dtype=np.int32))
-    cj = jnp.asarray(np.asarray(cand, dtype=np.int32))
-    okj = jnp.asarray(np.asarray(cand_ok, dtype=bool))
-    kj = jnp.asarray(np.asarray(cand_key, dtype=np.int32))
-    hj = jnp.asarray(np.asarray(have, dtype=bool))
-    fj = jnp.asarray(np.asarray(full, dtype=bool))
-    if b == "pallas":
-        out = _match_requests_pallas(oj, wj, bj, cj, okj, kj, hj, fj)
-    else:
-        out = _match_requests_jax(oj, wj, bj, cj, okj, kj, hj, fj)
-    return np.asarray(out, dtype=np.int32)
+    # the pallas backend shares the jax walk: its per-row dynamic lane
+    # gather and in-kernel argmin have no Mosaic lowering.  Pad rows get
+    # no walk and no budget, pad candidates are -1 and unusable, pad
+    # holder rows hold nothing: none of them can be picked.
+    r, p = np.shape(orders)
+    c = cand.shape[1]
+    n = np.shape(have)[0]
+    rb, cb, nb = _bucket(r), _bucket(c), _bucket(n)
+    out = _match_requests_jax(
+        _pad(np.asarray(orders, dtype=np.int32), (rb, p), 0),
+        _pad(np.asarray(n_walk, dtype=np.int32), (rb,), 0),
+        _pad(np.asarray(budgets, dtype=np.int32), (rb,), 0),
+        _pad(np.asarray(cand, dtype=np.int32), (rb, cb), -1),
+        _pad(np.asarray(cand_ok, dtype=bool), (rb, cb), False),
+        _pad(np.asarray(cand_key, dtype=np.int32), (rb, cb), KEY_INF32),
+        _pad(np.asarray(have, dtype=bool), (nb, p), False),
+        _pad(np.asarray(full, dtype=bool), (nb,), False))
+    return _fetch("match_requests", out)[:r]
 
 
 # ===================== endgame holder top-k ============================= #
@@ -614,21 +600,18 @@ def holder_topk_np(keys: np.ndarray, k: int) -> np.ndarray:
     return out
 
 
-if _HAVE_JAX:
-    from functools import partial as _partial
-
-    @_partial(jax.jit, static_argnames=("k",))
-    def _holder_topk_jax(keys, k: int):
-        n, p = keys.shape
-        kk = min(int(k), n)
-        # top_k takes the LARGEST along the last axis; negate + transpose
-        vals, idx = jax.lax.top_k(-keys.astype(jnp.int32).T, kk)
-        valid = -vals < jnp.int32(KEY_INF32)
-        out = jnp.where(valid, idx, -1).astype(jnp.int32).T   # (kk, P)
-        if kk < int(k):
-            pad = jnp.full((int(k) - kk, p), -1, dtype=jnp.int32)
-            out = jnp.concatenate([out, pad], axis=0)
-        return out
+@partial(jax.jit, static_argnames=("k",))
+def _holder_topk_jax(keys, k: int):
+    n, p = keys.shape
+    kk = min(int(k), n)
+    # top_k takes the LARGEST along the last axis; negate + transpose
+    vals, idx = jax.lax.top_k(-keys.astype(jnp.int32).T, kk)
+    valid = -vals < jnp.int32(KEY_INF32)
+    out = jnp.where(valid, idx, -1).astype(jnp.int32).T   # (kk, P)
+    if kk < int(k):
+        pad = jnp.full((int(k) - kk, p), -1, dtype=jnp.int32)
+        out = jnp.concatenate([out, pad], axis=0)
+    return out
 
 
 def holder_topk(keys: np.ndarray, k: int,
@@ -637,10 +620,13 @@ def holder_topk(keys: np.ndarray, k: int,
     if b == "numpy":
         return holder_topk_np(keys, k)
     # the pallas backend shares the jax path (same discipline as
-    # choke_order: selection/sort primitives stay in XLA)
+    # choke_order: selection/sort primitives stay in XLA).  Pad holder
+    # rows carry KEY_INF32, so they only ever surface as -1.
+    n, p = np.shape(keys)
     out = _holder_topk_jax(
-        jnp.asarray(np.asarray(keys, dtype=np.int32)), int(k))
-    return np.asarray(out, dtype=np.int32)
+        _pad(np.asarray(keys, dtype=np.int32), (_bucket(n), p), KEY_INF32),
+        int(k))
+    return _fetch("holder_topk", out)
 
 
 # ===================== scalar-compatible wrappers ======================= #

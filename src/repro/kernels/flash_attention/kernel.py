@@ -9,7 +9,8 @@ Causal/sliding-window masking is positional via iota; fully-masked kv blocks
 are skipped with pl.when so the kernel does no dead MXU work beyond the
 diagonal half-bricks.
 
-Validated on CPU with interpret=True against ref.mha_reference and against
+Interpreted on CPU, compiled on TPU (`repro.kernels.pallas_on_platform`).
+Validated on CPU against ref.mha_reference and against
 the custom-vjp jnp implementation in ops.py (which is also the TPU-side
 fallback when use_pallas=False).
 """
@@ -24,10 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams; accept either
-# spelling so the kernel builds on both old (<=0.4.37) and new images
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
+from repro.kernels import pallas_on_platform
+
 
 NEG_INF = -1e30
 
@@ -89,8 +88,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      causal: bool = True, window: int = 0,
-                     block_q: int = 128, block_k: int = 128,
-                     interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                     block_q: int = 128, block_k: int = 128
+                     ) -> Tuple[jax.Array, jax.Array]:
     """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (out, lse)."""
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
@@ -111,35 +110,38 @@ def flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _fwd_kernel, scale=1.0 / math.sqrt(D), causal=causal, window=window,
         block_q=block_q, block_k=block_k, n_kv=nk, seq_kv=Skv)
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, Hq, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, i, j, G=G: (b, j, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, i, j, G=G: (b, j, h // G, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, h, i, j: (b, i, h)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Sq + pad_q, Hq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Sq + pad_q, Hq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
+    def run(q, k, v, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(B, Hq, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, 1, D),
+                             lambda b, h, i, j: (b, i, h, 0)),
+                pl.BlockSpec((1, block_k, 1, D),
+                             lambda b, h, i, j, G=G: (b, j, h // G, 0)),
+                pl.BlockSpec((1, block_k, 1, D),
+                             lambda b, h, i, j, G=G: (b, j, h // G, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, 1, D),
+                             lambda b, h, i, j: (b, i, h, 0)),
+                pl.BlockSpec((1, block_q, 1),
+                             lambda b, h, i, j: (b, i, h)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, Sq + pad_q, Hq, D), q.dtype),
+                jax.ShapeDtypeStruct((B, Sq + pad_q, Hq), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, D), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=interpret,
+        )(q, k, v)
+
+    out, lse = pallas_on_platform(run, q, k, v)
     return out[:, :Sq], lse[:, :Sq]

@@ -22,10 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams; accept either
-# spelling so the kernel builds on both old (<=0.4.37) and new images
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
+from repro.kernels import pallas_on_platform
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref,
@@ -78,7 +75,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref,
 
 
 def ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
-               Cm: jax.Array, chunk: int = 128, interpret: bool = True
+               Cm: jax.Array, chunk: int = 128
                ) -> Tuple[jax.Array, jax.Array]:
     """x: (B,S,H,P); dt: (B,S,H); A: (H,); Bm/Cm: (B,S,G,N) with G | H.
 
@@ -98,29 +95,35 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     nc = Sp // chunk
 
     kernel = functools.partial(_ssd_kernel, n_chunks=nc, chunk=chunk)
-    y, fin = pl.pallas_call(
-        kernel,
-        grid=(Bsz, H, nc),
-        in_specs=[
-            pl.BlockSpec((1, chunk, 1, Pd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
-            pl.BlockSpec((1, chunk, 1, N),
-                         lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, 1, Pd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, 1, Pd, N), lambda b, h, c: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bsz, Sp, H, Pd), x.dtype),
-            jax.ShapeDtypeStruct((Bsz, H, Pd, N), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((Pd, N), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+
+    def run(x, dt, A, Bm, Cm, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(Bsz, H, nc),
+            in_specs=[
+                pl.BlockSpec((1, chunk, 1, Pd),
+                             lambda b, h, c: (b, c, h, 0)),
+                pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
+                pl.BlockSpec((1,), lambda b, h, c: (h,)),
+                pl.BlockSpec((1, chunk, 1, N),
+                             lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
+                pl.BlockSpec((1, chunk, 1, N),
+                             lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, chunk, 1, Pd),
+                             lambda b, h, c: (b, c, h, 0)),
+                pl.BlockSpec((1, 1, Pd, N), lambda b, h, c: (b, h, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((Bsz, Sp, H, Pd), x.dtype),
+                jax.ShapeDtypeStruct((Bsz, H, Pd, N), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((Pd, N), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(x, dt, A, Bm, Cm)
+
+    y, fin = pallas_on_platform(run, x, dt, A, Bm, Cm)
     return y[:, :S], fin

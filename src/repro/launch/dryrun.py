@@ -20,7 +20,7 @@ import traceback
 import jax
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config
-from repro.launch.mesh import HARDWARE, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import step_args_abstract
 from repro.launch import hlo_analysis
 from repro.optim.adamw import AdamWConfig

@@ -6,7 +6,7 @@ import json
 import os
 
 from repro.launch.hlo_analysis import collective_link_bytes
-from repro.launch.mesh import HARDWARE
+from repro.launch.mesh import V5E, hardware
 from repro.launch.roofline import analyze_cell, load_cells, markdown_table
 
 
@@ -21,15 +21,16 @@ def load(art, arch, shape, mesh="16x16", variant=None):
 
 def terms(rec):
     h = rec["hlo"]
+    hw = hardware(V5E)
     link = collective_link_bytes(h.get("coll_ops", []))
     return {
         "flops": h["flops"],
         "bytes": h["bytes_accessed"],
         "coll_raw": h["collective_bytes"],
         "coll_link": link,
-        "compute_s": h["flops"] / HARDWARE["peak_flops_bf16"],
-        "memory_s": h["bytes_accessed"] / HARDWARE["hbm_bandwidth"],
-        "coll_s": link / HARDWARE["ici_link_bandwidth"],
+        "compute_s": h["flops"] / hw["peak_flops_bf16"],
+        "memory_s": h["bytes_accessed"] / hw["hbm_bandwidth"],
+        "coll_s": link / hw["ici_link_bandwidth"],
         "temp_gib": rec["memory"]["temp_bytes"] / 2**30,
         "kinds": h.get("collectives", {}),
     }

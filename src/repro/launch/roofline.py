@@ -21,7 +21,7 @@ from typing import Dict, List, Optional
 
 from repro.configs.base import SHAPES, get_config
 from repro.launch.hlo_analysis import collective_link_bytes
-from repro.launch.mesh import HARDWARE
+from repro.launch.mesh import V5E, hardware
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -113,9 +113,10 @@ def analyze_cell(rec: dict) -> Optional[CellRoofline]:
         return None
     hlo = rec["hlo"]
     n_dev = hlo.get("n_devices", 256)
-    peak = HARDWARE["peak_flops_bf16"]
-    hbm = HARDWARE["hbm_bandwidth"]
-    link = HARDWARE["ici_link_bandwidth"]
+    hw = hardware(V5E)       # the dry-run cells model the v5e pod
+    peak = hw["peak_flops_bf16"]
+    hbm = hw["hbm_bandwidth"]
+    link = hw["ici_link_bandwidth"]
     compute_s = hlo["flops"] / peak
     memory_s = hlo["bytes_accessed"] / hbm
     link_bytes = collective_link_bytes(hlo.get("coll_ops", []))

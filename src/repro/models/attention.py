@@ -255,7 +255,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
              (batch_axes[0] if len(batch_axes) == 1 else tuple(batch_axes)))
     sspec = seq_axes[0] if len(seq_axes) == 1 else tuple(seq_axes)
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def local_fn(q_l, k_l, v_l, t_l):
         # shard index along the flattened seq axes
@@ -316,7 +316,7 @@ def _q_col_parallel(x: jax.Array, wq: jax.Array):
         return None
     bsp = (None if not data_axes else
            (data_axes[0] if len(data_axes) == 1 else data_axes))
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def f(x_l, wq_l):
         xg = jax.lax.all_gather(x_l, "model", axis=1, tiled=True)

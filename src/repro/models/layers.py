@@ -107,7 +107,7 @@ def embed_tokens(params: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array
         x = jnp.take(emb.astype(cfg.act_dtype), tokens, axis=0)
         return shard_act(x, "batch", "seq_act", None)
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mp = mesh.shape["model"]
     V_loc = V // mp
@@ -232,7 +232,7 @@ def row_parallel_proj(h: jax.Array, w: jax.Array, eq: str,
     bsp = (None if not data_axes else
            (data_axes[0] if len(data_axes) == 1 else data_axes))
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     h_spec = [bsp] + [None] * (h.ndim - 1)
@@ -281,7 +281,7 @@ def col_parallel_mlp_in(x: jax.Array, wg: jax.Array, wu: jax.Array):
         return None
     bsp = (None if not data_axes else
            (data_axes[0] if len(data_axes) == 1 else data_axes))
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def f(x_l, wg_l, wu_l):

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import mlp_specs, mlp_apply

@@ -92,6 +92,9 @@ class ServingEngine:
         if mesh is not None and pod_axis in getattr(mesh, "shape", {}):
             from repro.parallel.weight_torrent import torrent_broadcast
             params = torrent_broadcast(params, mesh, axis=pod_axis)
+        else:
+            # onto the device once, not re-sent with every decode step
+            params = jax.device_put(params)
         eng = cls(cfg, params, sc, mesh=mesh)
         eng.restore_extra = extra
         return eng

@@ -1,21 +1,5 @@
 import pytest
 
-try:
-    import jax  # noqa: F401
-    _HAVE_JAX = True
-except Exception:
-    _HAVE_JAX = False
-
-if not _HAVE_JAX:
-    # the fast protocol CI job installs no jax: keep pytest from even
-    # importing the jax-marked modules at collection time (-m deselection
-    # alone still imports them and dies on the ImportError)
-    collect_ignore = ["test_checkpoint_swarm.py", "test_infra.py",
-                      "test_kernels.py", "test_models.py",
-                      "test_parallel.py", "test_serving.py",
-                      "test_trainer.py"]
-
-
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_makereport(item, call):
     """Print the chaos seed (with a one-line repro command) on any failing

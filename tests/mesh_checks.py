@@ -16,8 +16,8 @@ import numpy as np
 
 
 def _mesh():
-    import jax
-    return jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def check_train_step_sharded_matches_single():
@@ -153,7 +153,8 @@ def check_torrent_broadcast():
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.parallel.weight_torrent import torrent_broadcast_pieces
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("pod", "data"))
     n, Pn, L = 4, 8, 32
     rng = np.random.RandomState(0)
     views = rng.randn(n, Pn, L).astype(np.float32)
@@ -256,7 +257,8 @@ def check_moe_int8_a2a_close_to_exact():
 def check_pipeline_parallel_matches_sequential():
     import jax, jax.numpy as jnp
     from repro.parallel.pipeline import pipeline_apply
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("pod", "data"))
     L, M, B, D = 4, 6, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     ws = jax.random.normal(ks[0], (L, D, D), jnp.float32) * 0.3
@@ -279,6 +281,44 @@ def check_pipeline_parallel_matches_sequential():
     err = float(jnp.max(jnp.abs(ref - out)))
     assert err < 1e-5, err
     print("OK pipeline parallel == sequential, err", err)
+
+
+def check_torrent_broadcast_tree():
+    """Pytree fan-out, leaf by leaf: only the seeder pod loads the bytes,
+    and every device ends up holding them as a replicated leaf."""
+    import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
+    from repro.parallel.weight_torrent import torrent_broadcast
+    mesh = make_mesh((4, 2), ("pod", "data"))
+    rng = np.random.RandomState(1)
+    tree = {"w": rng.randn(7, 13).astype(np.float32),
+            "b": rng.randn(5).astype(np.float32).astype(jnp.bfloat16),
+            "i": np.arange(9, dtype=np.int32).reshape(3, 3)}
+    out = torrent_broadcast(tree, mesh, axis="pod", seeder=1)
+    for k, leaf in tree.items():
+        got = out[k]
+        assert got.sharding.is_fully_replicated and got.dtype == leaf.dtype
+        assert len(got.addressable_shards) == 8
+        for s in got.addressable_shards:
+            assert np.asarray(s.data).tobytes() == leaf.tobytes(), k
+    print("OK torrent broadcast tree")
+
+
+def check_chip_smoke_fanout_tiny():
+    """chip_smoke.py --chips 4 path on 4 of the host devices, tiny model."""
+    import jax
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import tempfile
+    import chip_smoke
+    from repro.configs.base import get_config, reduced_config
+    cfg = reduced_config(get_config("qwen2-vl-2b"))
+    with tempfile.TemporaryDirectory() as d:
+        out = chip_smoke.phase_fanout(cfg=cfg, devices=jax.devices()[:4],
+                                      workdir=os.path.join(d, "w"),
+                                      n_requests=5, prompt_len=4, max_new=3)
+    assert [len(t) for t in out["tokens"]] == [3] * 5
+    print("OK chip_smoke fan-out tiny")
 
 
 CHECKS = {k[6:]: v for k, v in list(globals().items())

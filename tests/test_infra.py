@@ -146,7 +146,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch import hlo_analysis
-mesh = jax.make_mesh((4,), ("d",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("d",))
 x = jax.ShapeDtypeStruct((64, 64), jnp.float32,
                          sharding=NamedSharding(mesh, P("d", None)))
 def f(x):
@@ -162,3 +163,10 @@ print("OK", res["collectives"])
     p = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300, env=env)
     assert p.returncode == 0 and "OK" in p.stdout, p.stderr[-2000:]
+
+
+def test_hardware_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import V5E, hardware
+    assert hardware(V5E)["hbm_bytes"] == 16e9
+    with pytest.raises(KeyError, match="cpu"):
+        hardware("cpu")

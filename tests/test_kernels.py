@@ -1,22 +1,11 @@
-"""Kernel validation: Pallas (interpret=True) and jnp twins vs pure oracles,
-swept over shapes and dtypes."""
+"""Kernel validation: Pallas (interpreted on CPU) and jnp twins vs pure
+oracles, swept over shapes and dtypes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 pytestmark = pytest.mark.jax_slow
-
-from jax.experimental.pallas import tpu as pltpu
-
-# The kernels fall back to the old pltpu.TPUCompilerParams spelling when
-# the renamed CompilerParams is absent (jax <=0.4.37), so the Pallas paths
-# build on both spellings; skip only if pallas exposes neither.
-_HAS_PALLAS_COMPILER_PARAMS = (hasattr(pltpu, "CompilerParams")
-                               or hasattr(pltpu, "TPUCompilerParams"))
-needs_pallas = pytest.mark.skipif(
-    not _HAS_PALLAS_COMPILER_PARAMS,
-    reason="pallas lacks CompilerParams/TPUCompilerParams on this jax")
 
 from repro.kernels.flash_attention.kernel import flash_fwd_pallas
 from repro.kernels.flash_attention.ops import flash_attention
@@ -51,7 +40,6 @@ def test_flash_jnp_matches_reference(case, dtype):
     assert err < tol, (case, dtype, err)
 
 
-@needs_pallas
 @pytest.mark.parametrize("case", FLASH_CASES)
 def test_flash_pallas_matches_reference(case):
     B, Sq, Skv, Hq, Hkv, D, causal, window = case
@@ -106,9 +94,8 @@ def test_ssd_scan_and_pallas_match_naive(case):
     Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.5
     Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.5
     y0, s0 = ssd_naive(x, dt, A, Bm, Cm)
-    pairs = [ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)]
-    if _HAS_PALLAS_COMPILER_PARAMS:
-        pairs.append(ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk))
+    pairs = [ssd_scan(x, dt, A, Bm, Cm, chunk=chunk),
+             ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk)]
     for y, s in pairs:
         assert float(jnp.max(jnp.abs(y0 - y))) < 1e-3
         assert float(jnp.max(jnp.abs(s0 - s))) < 1e-3
