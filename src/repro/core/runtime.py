@@ -25,6 +25,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
+from repro.core import trace
 from repro.core.faults import FaultPlan
 from repro.core.messages import Msg
 from repro.core.topology import Topology
@@ -145,9 +146,8 @@ class SimRuntime(Runtime):
         self.speed: Dict[str, float] = {}
         # total events executed by run() — simulator-throughput metric
         self.events_processed = 0
-        # run_batched wall split: message-burst drains vs on_tick passes
+        # wall seconds of run_batched's event bursts (its swarm.drain spans)
         self.batched_drain_s = 0.0
-        self.batched_tick_s = 0.0
         # per-node egress accounting and uplink/downlink-contention state
         self.tx_bytes: Dict[str, int] = {}
         self._uplink_free: Dict[str, float] = {}
@@ -444,15 +444,13 @@ class SimRuntime(Runtime):
         cascades behave as in per-message mode; only the on_tick hook
         itself runs at quantized times.
 
-        Wall time is split into `batched_drain_s` (message bursts: the
-        per-event host-Python cost) and `batched_tick_s` (the on_tick
-        decision passes) so `swarm_bench --profile` can report where a
-        batched run actually spends its time."""
+        Each burst runs under a ``swarm.drain`` span (`core.trace`), which
+        counts its events in ``swarm.drain.events`` and adds its wall time
+        to `batched_drain_s`: the per-event host-Python cost."""
         n = 0
         heap = self._heap
         tick = max(float(tick_s), 1e-9)
         stop = False
-        perf = time.perf_counter
         while heap and n < max_events and not stop:
             t0 = heap[0][0]
             if until is not None and t0 > until:
@@ -460,23 +458,24 @@ class SimRuntime(Runtime):
             boundary = t0 + tick
             if until is not None:
                 boundary = min(boundary, until)
-            w0 = perf()
-            while heap and heap[0][0] <= boundary and n < max_events:
-                t, _, fn, args = heapq.heappop(heap)
-                self._t = t
-                fn(*args)
-                n += 1
-                if stop_when is not None and n % 64 == 0 and stop_when():
-                    stop = True
-                    break
-            self.batched_drain_s += perf() - w0
+            n0 = n
+            with trace.span("swarm.drain") as burst:
+                while heap and heap[0][0] <= boundary and n < max_events:
+                    t, _, fn, args = heapq.heappop(heap)
+                    self._t = t
+                    fn(*args)
+                    n += 1
+                    if stop_when is not None and n % 64 == 0 \
+                            and stop_when():
+                        stop = True
+                        break
+            self.batched_drain_s += burst.seconds
+            trace.count("swarm.drain.events", n - n0)
             if stop:
                 break
             if on_tick is not None:
                 self._t = max(self._t, boundary)
-                w0 = perf()
                 on_tick(self._t)
-                self.batched_tick_s += perf() - w0
                 if stop_when is not None and stop_when():
                     break
         self.events_processed += n

@@ -83,16 +83,18 @@ pending-dict insertion order (same duplicate SET, different wire order).
 
 Every suppressed control message is counted in `coalesced`, every
 array-applied decision in `batch_ops`, and every incremental ledger
-update in `ledger_ops`; `tick()` also keeps wall-clock totals split into
-host-Python and kernel time for the `swarm_bench --profile` breakdown.
+update in `ledger_ops`.  `tick()` and its phases run under `core.trace`
+spans (``swarm.tick``, ``swarm.tick.<phase>``, ``swarm.kernel.<wrapper>``);
+the hub's wall-clock totals `prof_tick_s` and `prof_kernel_s` are summed
+from the tick and kernel spans as they close.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.swarm_kernels import (KEY_INF32, choke_order, cost_orders,
                                       get_backend, holder_topk, island_has,
                                       match_requests, min_island_cost,
@@ -434,18 +436,18 @@ class SwarmHub:
         self.coalesced = 0                 # control messages replaced
         self.ledger_ops = 0                # incremental ledger updates
         self.ticks = 0
-        # per-tick wall-clock split for `swarm_bench --profile`
-        self.prof_tick_s = 0.0             # total time inside tick()
-        self.prof_kernel_s = 0.0           # time inside kernel calls
+        # wall seconds of this hub's tick and kernel spans
+        self.prof_tick_s = 0.0             # inside tick()
+        self.prof_kernel_s = 0.0           # inside kernel wrapper calls
         # topology (P4P mode): ALTO cost matrix folded into selection
         self.topology = None
         self.cost_matrix: Optional[np.ndarray] = None
 
     def _kernel(self, fn, *args, **kw):
-        """Run one kernel call under the profile clock."""
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        self.prof_kernel_s += time.perf_counter() - t0
+        """Run one kernel wrapper under its ``swarm.kernel.<name>`` span."""
+        with trace.span("swarm.kernel." + fn.__name__) as s:
+            out = fn(*args, **kw)
+        self.prof_kernel_s += s.seconds
         return out
 
     # ========================= registration ============================= #
@@ -1369,24 +1371,30 @@ class SwarmHub:
     # ============================== tick ================================ #
     def tick(self, now: float) -> None:
         """One batched decision pass over every registered swarm."""
-        t0 = time.perf_counter()
         self.ticks += 1
-        for st in self.states.values():
-            if st.n == 0:
-                continue
-            for i in st.newly_full:
-                self._release_slots(st, i)
-            st.newly_full.clear()
-            if self._cfg is not None and getattr(self._cfg, "choke", True):
-                self._grants(st)
-                interval = float(
-                    getattr(self._cfg, "rechoke_interval_s", 10.0))
-                if now - st.last_rechoke >= interval:
-                    st.last_rechoke = now
-                    self._rechoke(st, now)
-            self._pump(st, now)
-            self._endgame(st, now)
-        self.prof_tick_s += time.perf_counter() - t0
+        with trace.span("swarm.tick", tick=self.ticks) as s:
+            for st in self.states.values():
+                if st.n == 0:
+                    continue
+                with trace.span("swarm.tick.release"):
+                    for i in st.newly_full:
+                        self._release_slots(st, i)
+                    st.newly_full.clear()
+                if self._cfg is not None \
+                        and getattr(self._cfg, "choke", True):
+                    with trace.span("swarm.tick.grants"):
+                        self._grants(st)
+                    interval = float(
+                        getattr(self._cfg, "rechoke_interval_s", 10.0))
+                    if now - st.last_rechoke >= interval:
+                        st.last_rechoke = now
+                        with trace.span("swarm.tick.rechoke"):
+                            self._rechoke(st, now)
+                with trace.span("swarm.tick.pump"):
+                    self._pump(st, now)
+                with trace.span("swarm.tick.endgame"):
+                    self._endgame(st, now)
+        self.prof_tick_s += s.seconds
 
     # ====================== queries / test bridges ====================== #
     def _find(self, app_id: str, node_id: str) -> Optional[SwarmState]:

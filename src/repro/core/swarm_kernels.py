@@ -34,12 +34,17 @@ pad every varying dimension to a power-of-two bucket (pad rows and
 candidates are inert), so a run compiles each kernel once per bucket, not
 once per tick.  Differential tests (tests/test_swarm_batch.py) assert all
 backends reproduce the scalar decisions bit-for-bit.
+
+Each jitted kernel compiles to a module named ``jit_swarm_<kernel>``
+(`_device_kernel`), the name a device trace is keyed on.  Every device
+call goes through `_call`, which times its dispatch and its fetch as
+`core.trace` spans and counts its bytes and its call.
 """
 from __future__ import annotations
 
 import collections
 import os
-from functools import partial
+from functools import partial, wraps
 from typing import List, Optional, Sequence
 
 import jax
@@ -47,6 +52,7 @@ import jax.experimental.pallas as pl
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.kernels import pallas_on_platform
 
 # sentinel key for pieces a row must not request (held, pending, invalid):
@@ -103,10 +109,35 @@ def _pad(a: np.ndarray, shape, fill) -> np.ndarray:
     return out
 
 
-def _fetch(name: str, out: jax.Array) -> np.ndarray:
-    """Device result -> host numpy, counted under the device's platform."""
+def _device_kernel(name: str, **jit_kw):
+    """Jit a kernel body under a stable name: the compiled module reads
+    ``jit_swarm_<name>`` and its operations sit in the ``swarm.<name>``
+    named scope, whatever the Python function is called."""
+    def wrap(body):
+        @wraps(body)
+        def scoped(*args, **kw):
+            with jax.named_scope(f"swarm.{name}"):
+                return body(*args, **kw)
+        scoped.__name__ = scoped.__qualname__ = f"swarm_{name}"
+        return jax.jit(scoped, **jit_kw)
+    return wrap
+
+
+def _call(kernel, *operands: np.ndarray, **static) -> np.ndarray:
+    """Run a `_device_kernel` on padded host operands and bring its
+    result back: the ``swarm.kernel.<name>.dispatch`` span covers the
+    call until it returns, the ``.fetch`` span the wait for the result
+    and its copy to the host.  Counts the bytes each way and the call
+    in `DEVICE_CALLS`, under the device's platform."""
+    name = kernel.__name__[len("swarm_"):]
+    trace.count(f"swarm.h2d_bytes.{name}", sum(a.nbytes for a in operands))
+    with trace.span(f"swarm.kernel.{name}.dispatch"):
+        out = kernel(*operands, **static)
     DEVICE_CALLS[name, next(iter(out.devices())).platform] += 1
-    return np.asarray(out)
+    with trace.span(f"swarm.kernel.{name}.fetch"):
+        host = np.asarray(out)
+    trace.count(f"swarm.d2h_bytes.{name}", host.nbytes)
+    return host
 
 
 # ====================== rarest-first scoring ============================ #
@@ -155,7 +186,7 @@ def _rarest_keys_pallas(counts, offsets, n: int, interpret: bool):
     )(counts, offsets)
 
 
-@partial(jax.jit, static_argnames=("n_pieces", "impl"))
+@_device_kernel("rarest_keys", static_argnames=("n_pieces", "impl"))
 def _rarest_keys_jax(counts, offsets, n_pieces: int, impl: str = "jnp"):
     # int32 throughout (jax runs without x64): the composite key needs
     # counts * n^2 < 2^31, which holds for every simulated swarm
@@ -188,10 +219,11 @@ def rarest_keys(counts: np.ndarray, offsets: np.ndarray, n_pieces: int,
     rows = offsets.shape[0]
     # reduce on the host: int64 rotations would wrap in int32 on device
     off = _pad(offsets % max(int(n_pieces), 1), (_bucket(rows),), 0)
-    out = _rarest_keys_jax(np.asarray(counts, dtype=np.int32),
-                           off.astype(np.int32), int(n_pieces),
-                           impl="pallas" if b == "pallas" else "jnp")
-    return _fetch("rarest_keys", out)[:rows].astype(np.int64)
+    out = _call(_rarest_keys_jax,
+                np.asarray(counts, dtype=np.int32), off.astype(np.int32),
+                n_pieces=int(n_pieces),
+                impl="pallas" if b == "pallas" else "jnp")
+    return out[:rows].astype(np.int64)
 
 
 def rarest_orders(missing: np.ndarray, counts: np.ndarray,
@@ -277,7 +309,7 @@ def _island_has_pallas(have, member, interpret: bool):
     )(member, have)
 
 
-@partial(jax.jit, static_argnames=("impl",))
+@_device_kernel("island_has", static_argnames=("impl",))
 def _island_has_jax(have, member, impl: str = "jnp"):
     if impl == "pallas":
         cnt = pallas_on_platform(_island_has_pallas,
@@ -301,9 +333,9 @@ def island_has(have: np.ndarray, member: np.ndarray,
     kb = -(-max(k, 1) // 8) * 8
     hv = _pad(np.asarray(have, dtype=bool), (nb, np.shape(have)[1]), False)
     mb = _pad(np.asarray(member, dtype=bool), (kb, nb), False)
-    out = _island_has_jax(hv, mb,
-                          impl="pallas" if b == "pallas" else "jnp")
-    return _fetch("island_has", out)[:k].astype(bool)
+    out = _call(_island_has_jax, hv, mb,
+                impl="pallas" if b == "pallas" else "jnp")
+    return out[:k].astype(bool)
 
 
 def min_island_cost(avail: np.ndarray, cost: np.ndarray) -> np.ndarray:
@@ -395,7 +427,7 @@ def choke_order_np(recv: np.ndarray, sent: np.ndarray, cand: np.ndarray,
     return order.astype(np.int32)
 
 
-@jax.jit
+@_device_kernel("choke_order")
 def _choke_order_jax(recv, sent, cand, ranks):
     r1 = jnp.where(cand, recv, -1.0)
     r2 = jnp.where(cand, sent, -1.0)
@@ -425,11 +457,12 @@ def choke_order(recv: np.ndarray, sent: np.ndarray, cand: np.ndarray,
     shape = (_bucket(h), _bucket(c))
     rk = np.asarray(ranks, dtype=np.int32)
     rk = _pad(rk, shape if rk.ndim == 2 else shape[1:], 0)
-    out = _choke_order_jax(
+    out = _call(
+        _choke_order_jax,
         _pad(np.asarray(recv, dtype=np.float32), shape, 0.0),
         _pad(np.asarray(sent, dtype=np.float32), shape, 0.0),
         _pad(np.asarray(cand, dtype=bool), shape, False), rk)
-    return _fetch("choke_order", out)[:h, :c]
+    return out[:h, :c]
 
 
 # ==================== fused request matching ============================ #
@@ -500,7 +533,7 @@ def match_requests_np(orders: np.ndarray, n_walk: np.ndarray,
     return picks
 
 
-@jax.jit
+@_device_kernel("match_requests")
 def _match_requests_jax(orders, n_walk, budgets, cand, cand_ok,
                         cand_key, have, full):
     R, P = orders.shape
@@ -551,7 +584,8 @@ def match_requests(orders: np.ndarray, n_walk: np.ndarray,
     c = cand.shape[1]
     n = np.shape(have)[0]
     rb, cb, nb = _bucket(r), _bucket(c), _bucket(n)
-    out = _match_requests_jax(
+    out = _call(
+        _match_requests_jax,
         _pad(np.asarray(orders, dtype=np.int32), (rb, p), 0),
         _pad(np.asarray(n_walk, dtype=np.int32), (rb,), 0),
         _pad(np.asarray(budgets, dtype=np.int32), (rb,), 0),
@@ -560,7 +594,7 @@ def match_requests(orders: np.ndarray, n_walk: np.ndarray,
         _pad(np.asarray(cand_key, dtype=np.int32), (rb, cb), KEY_INF32),
         _pad(np.asarray(have, dtype=bool), (nb, p), False),
         _pad(np.asarray(full, dtype=bool), (nb,), False))
-    return _fetch("match_requests", out)[:r]
+    return out[:r]
 
 
 # ===================== endgame holder top-k ============================= #
@@ -600,7 +634,7 @@ def holder_topk_np(keys: np.ndarray, k: int) -> np.ndarray:
     return out
 
 
-@partial(jax.jit, static_argnames=("k",))
+@_device_kernel("holder_topk", static_argnames=("k",))
 def _holder_topk_jax(keys, k: int):
     n, p = keys.shape
     kk = min(int(k), n)
@@ -623,10 +657,10 @@ def holder_topk(keys: np.ndarray, k: int,
     # choke_order: selection/sort primitives stay in XLA).  Pad holder
     # rows carry KEY_INF32, so they only ever surface as -1.
     n, p = np.shape(keys)
-    out = _holder_topk_jax(
+    return _call(
+        _holder_topk_jax,
         _pad(np.asarray(keys, dtype=np.int32), (_bucket(n), p), KEY_INF32),
-        int(k))
-    return _fetch("holder_topk", out)
+        k=int(k))
 
 
 # ===================== scalar-compatible wrappers ======================= #
