@@ -52,9 +52,10 @@ counterpart:
                           optimistic-unchoke rotation;
       4. pump           — piece orders from ONE `rarest_orders` kernel
                           call; holder matching for ALL rows in one
-                          fused `match_requests` kernel that walks order
-                          positions (<= P vectorized steps independent
-                          of N), candidates taken straight from the
+                          fused `match_requests` kernel that walks by
+                          picks (at most the pipeline budget plus one
+                          vectorized passes, independent of N and P),
+                          candidates taken straight from the
                           unchoke adjacency and the busy ledger;
       5. endgame        — rows whose every missing piece is in flight
                           (pure ledger-counter selection) duplicate
@@ -1240,6 +1241,7 @@ class SwarmHub:
                 budgets[idx], cand.astype(np.int32), ok,
                 key.astype(np.int32), st.have[:n], st.full[:n],
                 backend=self.backend)
+            most = made = 0
             for kk, k in enumerate(idx.tolist()):
                 pk = picks[kk]
                 got = np.nonzero(pk >= 0)[0]
@@ -1247,6 +1249,12 @@ class SwarmHub:
                                 for g in got.tolist()]
                 starved_out[k] = (got.size < n_missing[k]
                                   and got.size < budgets[k])
+                most = max(most, got.size)
+                made += got.size
+            # the device walk makes one pass per pick of its busiest
+            # row, then one that finds nothing
+            trace.count("swarm.match.steps", most + 1)
+            trace.count("swarm.match.picks", made)
 
     def _endgame(self, st: SwarmState, now: float) -> None:
         """Fused endgame: row selection is pure ledger arithmetic

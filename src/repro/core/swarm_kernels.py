@@ -468,13 +468,24 @@ def choke_order(recv: np.ndarray, sent: np.ndarray, cand: np.ndarray,
 # ==================== fused request matching ============================ #
 # The array-native ledger (ISSUE 10) lets the hub's pump stage stop
 # walking per-node dicts: every selected row's holder choice becomes one
-# greedy walk over its piece order, executed for ALL rows as a loop over
-# order POSITIONS (at most P vectorized steps, independent of N — the
-# "host time sublinear in N" property).  Each step k picks, for every
-# still-active row, the lowest-keyed usable candidate holding that row's
-# k-th rarest piece, marks the holder busy (one in-flight request per
-# holder) and burns one pipeline-budget unit — exactly the scalar
-# `_match_row` walk.
+# greedy walk over its piece order, executed for ALL rows at once
+# (independent of N — the "host time sublinear in N" property).  At
+# order position k the walk picks, for every still-active row, the
+# lowest-keyed usable candidate holding that row's k-th rarest piece,
+# marks the holder busy (one in-flight request per holder) and burns one
+# pipeline-budget unit — exactly the scalar `_match_row` walk.
+#
+# The numpy reference walks order POSITIONS: at most P vectorized steps.
+# The device kernel walks PICKS: between two picks of a row neither its
+# busy holders nor its budget change, so its next pick is at the first
+# position at or after the last one where an unbusy candidate holds the
+# piece.  One pass finds that position for every row and picks there,
+# so a call takes one pass per pick of its busiest row plus one that
+# finds nothing — at most min(budget, C) + 1 passes, whatever P — and
+# makes the same decisions as the position walk, bit for bit.  It works
+# in piece space: one sort of each row's order gives every piece's
+# position, and each pass is elementwise work and reductions over the
+# (R, C, P) holdings, with no gather.
 #
 # Keys are the int32-safe encoding ``cost * 2^20 + rank`` (< 2^27): it
 # orders identically to the scalar engine's ``rank + cost * 2^32`` —
@@ -537,32 +548,44 @@ def match_requests_np(orders: np.ndarray, n_walk: np.ndarray,
 def _match_requests_jax(orders, n_walk, budgets, cand, cand_ok,
                         cand_key, have, full):
     R, P = orders.shape
+    C = cand.shape[1]
     safe = jnp.where(cand >= 0, cand, 0)
     hv = have[safe] | full[safe][:, :, None]             # (R, C, P)
+    # at[r, p]: the order position of piece p in row r (rows of
+    # `orders` are permutations); a per-element gather into order
+    # positions costs the chip more than the whole walk
+    at = jnp.argsort(orders, axis=1).astype(jnp.int32)
     inf = jnp.int32(KEY_INF32)
     key0 = jnp.where(cand_ok, cand_key.astype(jnp.int32), inf)
-    ridx = jnp.arange(R)
+    kpos = jnp.arange(P, dtype=jnp.int32)[None, :]
+    cpos = jnp.arange(C, dtype=jnp.int32)[None, :]
+    inwalk = at < n_walk[:, None]
 
-    def body(k, carry):
-        picks, taken, budget = carry
-        act = (budget > 0) & (k < n_walk) & ~jnp.all(taken, axis=1)
-        p = orders[:, k]
-        col = jnp.take_along_axis(
-            hv, p[:, None, None], axis=2)[:, :, 0]       # (R, C)
-        okk = ~taken & col & act[:, None]
-        sel = okk.any(axis=1)
-        c = jnp.argmin(jnp.where(okk, key0, inf), axis=1)
-        val = jnp.take_along_axis(cand, c[:, None], axis=1)[:, 0]
-        picks = picks.at[:, k].set(
-            jnp.where(sel, val, picks[:, k]))
-        taken = taken.at[ridx, c].set(taken[ridx, c] | sel)
-        budget = budget - sel.astype(budget.dtype)
-        return picks, taken, budget
+    def more(carry):
+        return carry[4]
+
+    def body(carry):
+        picks, taken, budget, pos, _ = carry
+        # each candidate's first usable position at or after pos; the
+        # row's next pick is at the least of them
+        win = inwalk & (at >= pos[:, None]) & (budget > 0)[:, None]
+        usable = ~taken[:, :, None] & hv & win[:, None, :]
+        first = jnp.min(jnp.where(usable, at[:, None, :], P), axis=2)
+        k = jnp.min(first, axis=1)
+        sel = k < P
+        c = jnp.argmin(jnp.where(first == k[:, None], key0, inf), axis=1)
+        hit = (cpos == c[:, None]) & sel[:, None]
+        val = jnp.max(jnp.where(hit, cand, -1), axis=1)
+        picks = jnp.where((kpos == k[:, None]) & sel[:, None],
+                          val[:, None], picks)
+        return (picks, taken | hit, budget - sel.astype(budget.dtype),
+                jnp.where(sel, k + 1, P), jnp.any(sel))
 
     picks0 = jnp.full((R, P), -1, dtype=jnp.int32)
-    picks, _, _ = jax.lax.fori_loop(
-        0, P, body,
-        (picks0, ~cand_ok, budgets.astype(jnp.int32)))
+    picks = jax.lax.while_loop(
+        more, body,
+        (picks0, ~cand_ok, budgets.astype(jnp.int32),
+         jnp.zeros((R,), jnp.int32), jnp.bool_(True)))[0]
     return picks
 
 
@@ -576,10 +599,12 @@ def match_requests(orders: np.ndarray, n_walk: np.ndarray,
             or cand.shape[1] == 0:
         return match_requests_np(orders, n_walk, budgets, cand,
                                  cand_ok, cand_key, have, full)
-    # the pallas backend shares the jax walk: its per-row dynamic lane
-    # gather and in-kernel argmin have no Mosaic lowering.  Pad rows get
-    # no walk and no budget, pad candidates are -1 and unusable, pad
-    # holder rows hold nothing: none of them can be picked.
+    # the pallas backend shares the jax walk: its row gather, sort and
+    # argmin stay in XLA.  The walk needs each row of `orders` to be a
+    # permutation of the pieces, as `rarest_orders` and `cost_orders`
+    # return.  Pad rows get no walk and no budget, pad candidates are -1
+    # and unusable, pad holder rows hold nothing: none of them can be
+    # picked.
     r, p = np.shape(orders)
     c = cand.shape[1]
     n = np.shape(have)[0]

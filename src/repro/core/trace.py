@@ -22,8 +22,11 @@ Span names:
   swarm.kernel.<kernel>.fetch         the wait for the result and its copy back
 
 Counters: ``swarm.drain.events`` (events drained),
-``swarm.h2d_bytes.<kernel>`` (operand bytes as padded) and
-``swarm.d2h_bytes.<kernel>`` (result bytes).
+``swarm.h2d_bytes.<kernel>`` (operand bytes as padded),
+``swarm.d2h_bytes.<kernel>`` (result bytes), and per `match_requests`
+call of `SwarmHub._match_fast` ``swarm.match.steps`` (the device walk's
+passes: the most picks of any row, plus the pass that finds nothing)
+and ``swarm.match.picks`` (picks made).
 """
 from __future__ import annotations
 

@@ -453,6 +453,112 @@ def test_fused_kernel_backends_agree_with_numpy():
             assert got.tolist() == tref.tolist(), b
 
 
+def _shaped_match_case(seed, R, C, P=256, N=64, dens=0.05, walk="mixed",
+                       budget="mixed", ok=0.85, cost=False):
+    """One `match_requests` case at a cell's widths: permutation orders
+    over P pieces; ``walk`` and ``budget`` give each row "mixed" (drawn
+    from 0..hi), "none", "part" (1..hi-1) or "full" (hi), where hi is P
+    for the walk and C + 3, more than the width, for the budget;
+    ``dens`` is the share of pieces a holder holds (0: no candidate
+    holds anything); ``cost`` gives keys ``cost * 2^20 + rank`` with
+    shared costs, else name ranks."""
+    rng = np.random.default_rng(seed)
+    orders = np.stack([rng.permutation(P) for _ in range(R)]) \
+        .astype(np.int32)
+    draw = {"mixed": lambda hi: rng.integers(0, hi + 1, R),
+            "none": lambda hi: np.zeros(R, np.int64),
+            "part": lambda hi: rng.integers(1, hi, R),
+            "full": lambda hi: np.full(R, hi)}
+    n_walk = draw[walk](P).astype(np.int32)
+    budgets = draw[budget](C + 3).astype(np.int32)
+    cand = np.stack([rng.choice(N, C, replace=False) for _ in range(R)]) \
+        .astype(np.int32)
+    cand[rng.random((R, C)) < 0.1] = -1
+    cand_ok = (cand >= 0) & (rng.random((R, C)) < ok)
+    rank = np.stack([rng.choice(1 << 20, C, replace=False)
+                     for _ in range(R)])
+    key = rank + (rng.integers(0, 4, (R, C)) << 20 if cost else 0)
+    cand_key = np.where(cand >= 0, key, sk.KEY_INF32).astype(np.int32)
+    have = rng.random((N, P)) < dens
+    full = (rng.random(N) < 0.03) & (dens > 0)
+    return orders, n_walk, budgets, cand, cand_ok, cand_key, have, full
+
+
+# (case kwargs, whether the reference makes picks there)
+_MATCH_SHAPES = {
+    "p256_w8": (dict(R=40, C=8), True),
+    "p256_w32": (dict(R=8, C=32), True),
+    "p256_w1": (dict(R=40, C=1), True),
+    "p256_w8_sparse": (dict(R=64, C=8, dens=0.004), True),
+    "cost_keys_w8": (dict(R=40, C=8, cost=True), True),
+    "cost_keys_w32": (dict(R=8, C=32, cost=True), True),
+    "starving": (dict(R=24, C=8, dens=0.0, walk="full"), False),
+    "walk_none": (dict(R=16, C=8, walk="none"), False),
+    "walk_part": (dict(R=16, C=8, walk="part"), True),
+    "walk_full": (dict(R=16, C=8, walk="full"), True),
+    "budget_none": (dict(R=16, C=8, budget="none"), False),
+    "budget_over_width": (dict(R=16, C=8, budget="full", dens=0.5), True),
+    "none_usable": (dict(R=16, C=8, ok=0.0), False),
+}
+
+
+@pytest.mark.jax_slow
+@pytest.mark.parametrize("name", sorted(_MATCH_SHAPES))
+def test_match_requests_jax_matches_numpy_at_cell_widths(name):
+    """The device walk (by picks) makes the reference walk's (by order
+    positions) decisions bit for bit at P = 256, candidate widths 1, 8
+    and 32 and both key encodings, with starving rows, walks of none,
+    part or all of P, spent and oversized budgets and no usable
+    candidate."""
+    kw, picks_some = _MATCH_SHAPES[name]
+    case = _shaped_match_case(sum(map(ord, name)), **kw)
+    ref = sk.match_requests_np(*case)
+    assert ref.tolist() == _match_requests_scalar(*case).tolist()
+    got = sk.match_requests(*case, backend="jax")
+    assert got.dtype == np.int32 and got.shape == ref.shape
+    assert got.tolist() == ref.tolist()
+    made = (ref >= 0).sum(axis=1)
+    assert bool(made.any()) == picks_some
+    if name == "budget_over_width":
+        usable = case[4].sum(axis=1)
+        assert (made == usable).any()       # a row took every candidate
+    assert (made <= np.minimum(case[2].clip(0), kw["C"])).all()
+
+
+@pytest.mark.jax_slow
+def test_match_walk_passes_bounded_by_picks_not_pieces(monkeypatch):
+    """A short batched Scenario VII on the jax backend: the hub's
+    ``swarm.match.steps`` counter holds each `match_requests` call to at
+    most ``piece_pipeline + 1`` passes of the device walk (128 order
+    positions here), and ``swarm.match.picks`` counts the picks the
+    calls returned, at most ``piece_pipeline`` a row."""
+    from benchmarks.paper_tables import scenario_vii
+    from repro.core import AgentConfig, swarm_arrays, trace
+    pipeline = AgentConfig().piece_pipeline
+    calls = []
+    inner = swarm_arrays.match_requests
+
+    def spy(orders, *args, **kw):
+        picks = inner(orders, *args, **kw)
+        calls.append((picks >= 0).sum(axis=1))
+        return picks
+    spy.__name__ = inner.__name__
+    monkeypatch.setattr(swarm_arrays, "match_requests", spy)
+    before = trace.snapshot()
+    got = scenario_vii(verbose=False, n_volunteers=24, n_pieces=128,
+                       image_mb=4, batched=True, backend="jax")
+    d = trace.delta(before, trace.snapshot())
+    assert got["ticks"] > 20 and calls
+    assert d["calls"]["swarm.kernel.match_requests"] == len(calls)
+    steps = [int(made.max()) + 1 for made in calls]
+    assert max(steps) <= pipeline + 1
+    assert d["counts"]["swarm.match.steps"] == sum(steps)
+    rows = sum(made.size for made in calls)
+    picks = d["counts"]["swarm.match.picks"]
+    assert picks == sum(int(made.sum()) for made in calls)
+    assert 0 < picks <= pipeline * rows
+
+
 # ========= ISSUE 10: array ledger vs scalar pending differential ======== #
 def _assert_ledger_matches_dicts(hub):
     """Every hub state's in-flight ledger must be entry-for-entry
